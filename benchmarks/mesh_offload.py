@@ -57,7 +57,7 @@ def _tensor(mesh):
     import jax.numpy as jnp
 
     from repro.core.aqua_tensor import AquaTensor, TransferMeter
-    a = AquaTensor(n_logical=256, page_shape=PAGE_SHAPE, local_slots=128,
+    a = AquaTensor(page_shape=PAGE_SHAPE, local_slots=128,
                    host_slots=128, dtype=jnp.float32, meter=TransferMeter(),
                    name="bench", mesh=mesh)
     a.add_remote_lease("donor0", 64)

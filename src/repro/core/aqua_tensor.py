@@ -159,8 +159,8 @@ class _MeterTxn:
 class AquaTensor:
     """A paged tensor with tiered page placement. Page payload: (page, d)."""
 
-    def __init__(self, *, n_logical: int, page_shape: Tuple[int, ...],
-                 local_slots: int, host_slots: int, dtype=jnp.bfloat16,
+    def __init__(self, *, page_shape: Tuple[int, ...], local_slots: int,
+                 host_slots: int, dtype=jnp.bfloat16,
                  meter: Optional[TransferMeter] = None, name: str = "kv",
                  mesh=None, faults=None):
         self.name = name
@@ -178,7 +178,11 @@ class AquaTensor:
         self.host_pool = np.zeros((host_slots,) + self.page_shape, self.dtype)
         self.remote_pools: Dict[str, jnp.ndarray] = {}
         self._remote_free: Dict[str, List[int]] = {}
-        # page_table[lp] = (tier, slot, donor_idx) ; -1 = unallocated
+        # page_table[lp] = (tier, slot, donor_idx) ; -1 = unallocated. One
+        # logical id per physical slot (a page always holds a slot, so more
+        # ids could never be allocated); every remote lease adds ids for its
+        # slots
+        n_logical = local_slots + host_slots
         self.page_table = np.full((n_logical, 3), -1, np.int64)
         # reference count per logical page: pages shared between block tables
         # (prefix sharing) are retained once per referencer and their physical
@@ -214,9 +218,14 @@ class AquaTensor:
         if self.reclaim is None or self._reclaiming:
             return 0
         self._reclaiming = True
+        # an eviction's demotion is its own migration, not a leg of the
+        # caller's park/restore: it is priced as its own message(s), one per
+        # physical transfer, even inside an open coalesce() transaction
+        txn, self.meter._txn = self.meter._txn, None
         try:
             return int(self.reclaim(tier, need))
         finally:
+            self.meter._txn = txn
             self._reclaiming = False
 
     # ------------------------------------------------------------------
@@ -249,6 +258,12 @@ class AquaTensor:
                 (slots,) + self.page_shape, self.dtype)
         self._remote_free[donor] = list(range(slots))[::-1]
         self.remote_capacity[donor] = int(slots)
+        self.page_table = np.concatenate(
+            [self.page_table, np.full((slots, 3), -1, np.int64)])
+        self.page_refs = np.concatenate(
+            [self.page_refs, np.zeros((slots,), np.int64)])
+        self.page_fill = np.concatenate(
+            [self.page_fill, np.ones((slots,), np.float64)])
         if donor not in self._donors:
             self._donors.append(donor)
 
@@ -766,15 +781,17 @@ class AquaTensor:
             # (tier, donor) — donor NAMES, not per-plane indices (two
             # planes may hold different donor lists when a lease's share
             # rounds to zero), and transfers touching different physical
-            # donors on EITHER end never fuse into one message
-            transfer_tier = REMOTE if (src_tier == REMOTE or dst_tier == REMOTE) else HOST
+            # donors on EITHER end never fuse into one message. A leg is a
+            # fabric message when either end is a donor, a host message
+            # otherwise (a REMOTE-bound move that spills to HOST never
+            # touches the fabric)
             src_name = self._donors[src_donor] if src_donor >= 0 else None
 
             def meter(lo, hi, dst, dst_name):
                 if dst_tier == src_tier or hi <= lo:
                     return
-                self.meter.record(float(fills[lo:hi].sum()), transfer_tier,
-                                  hi - lo,
+                link = REMOTE if REMOTE in (src_tier, dst) else HOST
+                self.meter.record(float(fills[lo:hi].sum()), link, hi - lo,
                                   group=(src_tier, src_name, dst, dst_name))
 
             # 3) acquire destination slots and scatter (metering per

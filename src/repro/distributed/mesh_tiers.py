@@ -46,7 +46,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.errors import LeaseRevokedError
-from repro.distributed.sharding import shard_map_compat
 
 
 def _bucket(n: int) -> int:
@@ -231,9 +230,9 @@ class MeshTierDomain:
                 keep = jax.lax.axis_index(axis) == dst
                 return jnp.where(keep, upd, pool_s[0])[None]
 
-            fn = jax.jit(shard_map_compat(
-                step, self.mesh, (P(axis), P(axis), P()), P(axis),
-                check=False))
+            fn = jax.jit(jax.shard_map(
+                step, mesh=self.mesh, in_specs=(P(axis), P(axis), P()),
+                out_specs=P(axis), check_vma=False))
             self._push_cache[key] = fn
         return fn, key
 
@@ -251,8 +250,9 @@ class MeshTierDomain:
                 stage = pool_s[0][slots]
                 return jax.lax.ppermute(stage[None], axis, [(src, 0)])
 
-            fn = jax.jit(shard_map_compat(
-                step, self.mesh, (P(axis), P()), P(axis), check=False))
+            fn = jax.jit(jax.shard_map(
+                step, mesh=self.mesh, in_specs=(P(axis), P()),
+                out_specs=P(axis), check_vma=False))
             self._pull_cache[key] = fn
         return fn, key
 

@@ -27,25 +27,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import ModelConfig, ShapeConfig
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, *, check: bool = True):
-    """``shard_map`` across jax versions: it lived in
-    ``jax.experimental.shard_map`` (kwarg ``check_rep``) before being
-    promoted to ``jax.shard_map`` (kwarg ``check_vma``).
-
-    Serving-side consumer: ``distributed/mesh_tiers.py`` wraps every
-    mesh-tier transfer leg (one ``ppermute`` each) in this, with
-    ``check=False`` — the legs are deliberately non-replicated (only the
-    serving/donor shard carries real data)."""
-    import inspect
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    kw = "check_vma" if "check_vma" in params else "check_rep"
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{kw: check})
-
-
 # parameter-name classes
 _IN_PROJ = ("wq", "wk", "wv", "up", "gate", "mix_w1", "decay_w1", "in_proj",
             "x_proj", "wdkv", "wuk", "wuv", "q_a", "v_a")

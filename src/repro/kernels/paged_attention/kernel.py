@@ -17,9 +17,9 @@ Five entry points:
     decode lane is a one-token row (``n_real = 1``) whose single query sits
     at absolute position ``q_start``; a chunk row is ``n_real`` real tokens
     at ``q_start + t``. The page loop and accumulators are the decode/chunk
-    kernels', so a fused engine step is bit-identical to the per-request
-    calls it replaces — while issuing ONE launch per layer instead of one
-    per admitted request.
+    kernels', so a row's reduction order does not depend on what else rides
+    the launch — while issuing ONE launch per layer instead of one per
+    admitted request.
   * ``append_kv``             — page-append writer: one decode token's K/V
     into each sequence's current page, in place via input-output aliasing
 
@@ -39,15 +39,17 @@ for the double-buffered page DMAs Mosaic inserts automatically.
 COMPILED pass: every attention entry point declares its grid semantics to
 the Mosaic compiler — the batch/packed-row axis and the kv-head axis are
 ``parallel`` (rows are independent; the compiler may partition them across
-the two TPU megacores), while the page-iteration axis is ``arbitrary`` (the
+TPU cores), while the page-iteration axis is ``arbitrary`` (the
 online-softmax accumulators in VMEM scratch carry across it, a sequential
-reduction). Megacore partitioning splits whole rows, never a row's page
-loop, so each row's reduction order — and therefore its output — is
-bit-identical to the interpret path and the per-request references. The
-one-launch engine step (``paged_mixed_attention_pool``) thus runs as a real
-partitioned kernel on TPU; on the CPU backend the same programs execute in
-interpret mode (``ops._on_cpu``), where the declared semantics are carried
-but unused.
+reduction). On TPU these are the kernels the fused engine step runs
+(``tpu_custom_call`` in the compiled step); on the CPU backend the same
+programs execute in interpret mode (``ops._on_cpu``). Both passes follow the
+same per-row page loop, but the compiled pass is its own program: Mosaic
+picks the matmul and exp lowering, so compiled outputs are checked against
+the references and the dense model path under tolerances, not bit for bit.
+On a TPU v5e at qwen1.5-0.5b widths in bf16 (``chip_smoke.py``), greedy
+tokens served through these kernels equal the dense path's argmax except
+at near-ties, whose logits differ by one bf16 step.
 """
 from __future__ import annotations
 
@@ -64,20 +66,7 @@ NEG_INF = -1e30
 # grid = (rows, kv heads, pages-per-sequence): rows/heads partition across
 # megacores, the page axis is the online-softmax reduction
 _POOL_SEMANTICS = ("parallel", "parallel", "arbitrary")
-
-
-def _compiler_params(dimension_semantics):
-    """Mosaic compiler params, tolerant of the class name moving between
-    jax releases (``TPUCompilerParams`` -> ``CompilerParams``); None when
-    neither exists so ``pallas_call`` falls back to default semantics."""
-    cls = (getattr(pltpu, "CompilerParams", None)
-           or getattr(pltpu, "TPUCompilerParams", None))
-    if cls is None:
-        return None
-    try:
-        return cls(dimension_semantics=tuple(dimension_semantics))
-    except TypeError:
-        return None
+_POOL_PARAMS = pltpu.CompilerParams(dimension_semantics=_POOL_SEMANTICS)
 
 
 def _paged_kernel(block_tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
@@ -195,7 +184,7 @@ def paged_attention_pool(q, kv_pool, block_tables, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
-        compiler_params=_compiler_params(_POOL_SEMANTICS),
+        compiler_params=_POOL_PARAMS,
         interpret=interpret,
     )(block_tables, lengths, qg, kv_pool)
     return out.reshape(B, H, hd)
@@ -292,7 +281,7 @@ def paged_prefill_attention_pool(q, kv_pool, block_tables, q_starts, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, Tc * G, hd), q.dtype),
-        compiler_params=_compiler_params(_POOL_SEMANTICS),
+        compiler_params=_POOL_PARAMS,
         interpret=interpret,
     )(block_tables, q_starts, qg, kv_pool)
     return (out.reshape(B, K, Tc, G, hd).transpose(0, 2, 1, 3, 4)
@@ -403,21 +392,24 @@ def paged_mixed_attention_pool(q, kv_pool, block_tables, q_starts, n_reals,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, K, Tc * G, hd), q.dtype),
-        compiler_params=_compiler_params(_POOL_SEMANTICS),
+        compiler_params=_POOL_PARAMS,
         interpret=interpret,
     )(block_tables, q_starts, n_reals, is_decode, qg, kv_pool)
     return (out.reshape(R, K, Tc, G, hd).transpose(0, 2, 1, 3, 4)
             .reshape(R, Tc, H, hd))
 
 
-def _append_kernel(slots_ref, offs_ref, k_ref, v_ref, pool_ref, out_ref, *,
-                   page: int):
-    """Copy the target page block, then overwrite one token row of K and V."""
-    b = pl.program_id(0)
-    off = offs_ref[b]
-    out_ref[...] = pool_ref[...]
-    out_ref[0, 0, :, pl.ds(off, 1), :] = k_ref[0][:, None, :]
-    out_ref[0, 1, :, pl.ds(off, 1), :] = v_ref[0][:, None, :]
+def _append_kernel(slots_ref, offs_ref, k_ref, v_ref, pool_ref, out_ref):
+    """Rewrite the target page block with one token row of K and V replaced.
+
+    The row is chosen by a select over the page axis rather than a dynamic
+    one-row store: Mosaic refuses a store at a dynamic sublane offset into
+    a packed (bf16) page, while the select lowers for every dtype."""
+    off = offs_ref[pl.program_id(0)]
+    for half, new_ref in ((0, k_ref), (1, v_ref)):
+        block = pool_ref[0, half]                          # (K, page, hd)
+        rows = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1)
+        out_ref[0, half] = jnp.where(rows == off, new_ref[0], block)
 
 
 def append_kv(kv_pool, k_new, v_new, slots, offsets, *, interpret: bool = False):
@@ -430,12 +422,16 @@ def append_kv(kv_pool, k_new, v_new, slots, offsets, *, interpret: bool = False)
     """
     P, _, K, page, hd = kv_pool.shape
     B = k_new.shape[0]
+    # one (K, 1, hd) row block per lane: the select broadcasts it over the
+    # page axis inside the kernel
+    k_new = k_new.astype(kv_pool.dtype).reshape(B, K, 1, hd)
+    v_new = v_new.astype(kv_pool.dtype).reshape(B, K, 1, hd)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                 # slots, offsets
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, K, hd), lambda b, s, o: (b, 0, 0)),       # k_new
-            pl.BlockSpec((1, K, hd), lambda b, s, o: (b, 0, 0)),       # v_new
+            pl.BlockSpec((1, K, 1, hd), lambda b, s, o: (b, 0, 0, 0)),  # k_new
+            pl.BlockSpec((1, K, 1, hd), lambda b, s, o: (b, 0, 0, 0)),  # v_new
             pl.BlockSpec((1, 2, K, page, hd),
                          lambda b, s, o: (s[b], 0, 0, 0, 0)),          # pool
         ],
@@ -443,7 +439,7 @@ def append_kv(kv_pool, k_new, v_new, slots, offsets, *, interpret: bool = False)
                                lambda b, s, o: (s[b], 0, 0, 0, 0)),
     )
     return pl.pallas_call(
-        functools.partial(_append_kernel, page=page),
+        _append_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(kv_pool.shape, kv_pool.dtype),
         input_output_aliases={4: 0},           # pool (incl. scalar args) -> out
@@ -482,7 +478,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
-        compiler_params=_compiler_params(_POOL_SEMANTICS),
+        compiler_params=_POOL_PARAMS,
         interpret=interpret,
     )(block_tables, lengths, qg, k_pages, v_pages)
     return out.reshape(B, H, hd)

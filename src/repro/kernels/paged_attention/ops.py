@@ -3,11 +3,11 @@
 These are the ops the serving hot path calls. Backend policy — enforced by
 a CI grep-guard (no hard-coded interpreter pin anywhere under ``src/``):
 
-  * On TPU the kernels run COMPILED, with megacore/grid partitioning
+  * On TPU the kernels run COMPILED (Mosaic), with grid partitioning
     declared over the packed row and kv-head axes
-    (``kernel._POOL_SEMANTICS``) — partitioning splits whole rows, never a
-    row's page loop, so compiled outputs are bit-identical to interpret
-    mode and the per-request references.
+    (``kernel._POOL_SEMANTICS``). Compiled outputs match the ``ref.py``
+    oracles and the dense model path under tolerances; they are not
+    promised bit-identical to interpret mode.
   * On the CPU backend the same programs run in interpret mode. The ONLY
     sanctioned way to request it on an engine-path call is this module's
     ``interpret=_on_cpu()`` — hard-coding the flag to ``True`` would

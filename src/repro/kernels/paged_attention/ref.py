@@ -1,11 +1,10 @@
 """Pure-jnp oracles for paged attention.
 
-These are the correctness anchors for BOTH kernel passes: the interpret-mode
-path the CPU CI runs AND the compiled TPU pass (megacore-partitioned grid,
-``kernel._POOL_SEMANTICS``) must match these references bit-for-bit — the
-kernels' page-loop reduction order deliberately mirrors the f32 online
-softmax written here, and megacore partitioning only ever splits whole
-rows, so no legal lowering may reassociate a row's reduction.
+These are the correctness anchors for BOTH kernel passes. The
+interpret-mode path the CPU tests run follows the same f32 online softmax
+page loop as the compiled TPU pass; the compiled pass (Mosaic's own matmul
+and exp lowering, ``kernel._POOL_SEMANTICS`` grid) is held to these oracles
+under tolerances, not bit for bit.
 """
 from __future__ import annotations
 

@@ -191,13 +191,9 @@ def moe_apply(params, cfg: ModelConfig, x: jnp.ndarray, *,
     if has_gate:
         args.append(params["gate"])
         in_specs.append(P(tp, None, None))
-    from repro.distributed.sharding import shard_map_compat
-    y, aux = shard_map_compat(
-        ep, mesh,
-        tuple(in_specs),
-        (P(dp, None, None), P()),
-        check=False,
-    )(*args)
+    y, aux = jax.shard_map(
+        ep, mesh=mesh, in_specs=tuple(in_specs),
+        out_specs=(P(dp, None, None), P()), check_vma=False)(*args)
     if m.n_shared_experts:
         y = y + _shared_experts(params, cfg, x.reshape(N, d)).reshape(B, T, d)
     return y, aux.astype(jnp.float32)

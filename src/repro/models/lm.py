@@ -763,17 +763,17 @@ def serve_step_paged(params, cfg: ModelConfig, tokens, pools, block_tables,
     chunk rows covering absolute positions < P take these embeddings.
     -> (logits (R, V) of each row's last real token, updated pools)
 
-    Row r's logits are bit-identical to the per-request entry point that
-    row replaces (``decode_step_paged`` / ``prefill_chunk_paged``): each
+    Row r's logits match the per-request entry point that row replaces
+    (``decode_step_paged`` / ``prefill_chunk_paged``) to within f32
+    rounding — the fused call is an XLA program of another shape: each
     plane dispatches decode and chunk row regions through its per-request
     math, and the fused attention kernel's per-row reduction order is the
     per-request kernels'. What changes is the launch count: one jitted
     dispatch and one attention launch per layer for the WHOLE step, instead
     of one call per admitted request's chunk plus one more for decode. On
-    TPU that launch is the COMPILED ``paged_mixed_attention_pool`` pass —
-    megacore-partitioned across the packed row axis (still bit-identical:
-    partitioning splits whole rows, never a row's page loop); interpret
-    mode is CPU-only (``ops._on_cpu``).
+    TPU that launch is the COMPILED ``paged_mixed_attention_pool`` pass,
+    partitioned across the packed row axis (whole rows, never a row's page
+    loop); interpret mode is CPU-only (``ops._on_cpu``).
     """
     assert supports_paged(cfg), f"{cfg.name}: not paged-servable"
     TRACE_COUNTS["serve_step"] += 1

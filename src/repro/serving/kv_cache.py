@@ -138,8 +138,8 @@ class PagedStateRuntime:
 
     def __init__(self, cfg: ModelConfig, *, max_seq: int,
                  page_tokens: int = 8, local_pages: Optional[int] = None,
-                 host_pages: int = 8192, n_logical: int = 16384,
-                 max_running: int = 4, meter: Optional[TransferMeter] = None,
+                 host_pages: int = 8192, max_running: int = 4,
+                 meter: Optional[TransferMeter] = None,
                  prefix_sharing: bool = True, prefix_cache: bool = True,
                  mesh=None):
         """Build one AquaTensor pool per page plane of ``cfg``'s family.
@@ -153,7 +153,6 @@ class PagedStateRuntime:
                 budget the schedulers plan against); default sizes for
                 ``max_running`` full-length requests.
             host_pages: host-tier slots per plane (the PCIe fallback).
-            n_logical: logical page ids per plane.
             max_running: used only to size default pools.
             meter: shared ``TransferMeter``; a fresh one by default.
             prefix_sharing: enable the copy-on-write prefix index. Forced
@@ -241,7 +240,9 @@ class PagedStateRuntime:
                 page_shape = spec["shape"]
                 per_req = n_layers
                 slots = max_running * per_req + 1
-            aqua = AquaTensor(n_logical=n_logical, page_shape=page_shape,
+            # logical ids: one per LOCAL and host slot, plus one per slot of
+            # every remote lease added later — the whole parked population
+            aqua = AquaTensor(page_shape=page_shape,
                               local_slots=slots, host_slots=host_pages,
                               dtype=spec["dtype"], meter=self.meter,
                               name=f"{cfg.name}/{name}", mesh=mesh)

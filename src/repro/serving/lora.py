@@ -83,7 +83,7 @@ class AdapterCache:
         self.capacity = capacity_local
         self.page_elems = page_elems
         self.aqua = AquaTensor(
-            n_logical=4096, page_shape=(page_elems,),
+            page_shape=(page_elems,),
             local_slots=max(capacity_local * 2, 4), host_slots=4096,
             dtype=dtype, meter=meter, name="lora")
         self._parked: Dict[int, tuple] = {}

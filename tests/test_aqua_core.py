@@ -20,7 +20,7 @@ from repro.core.simulator import (Request, ServingSimulator,
 # AquaTensor
 # ---------------------------------------------------------------------------
 def _mk_tensor(**kw):
-    args = dict(n_logical=32, page_shape=(4, 8), local_slots=8, host_slots=32,
+    args = dict(page_shape=(4, 8), local_slots=8, host_slots=32,
                 dtype=jnp.float32)
     args.update(kw)
     return AquaTensor(**args)

@@ -25,7 +25,7 @@ ARCH = "qwen1.5-0.5b"
 
 
 def _tensor(**kw):
-    args = dict(n_logical=64, page_shape=(4,), local_slots=8, host_slots=8,
+    args = dict(page_shape=(4,), local_slots=8, host_slots=8,
                 dtype=jnp.float32, meter=TransferMeter())
     args.update(kw)
     return AquaTensor(**args)
@@ -57,6 +57,10 @@ def test_error_hierarchy():
 # ---------------------------------------------------------------------------
 def test_allocate_rollback_when_tiers_exhaust_midway():
     t = _tensor(local_slots=3, host_slots=2)     # 5 physical slots total
+    # a lease that shrank away leaves more logical ids than slots, so the
+    # allocation runs out of slots mid-way rather than out of ids up front
+    t.add_remote_lease("d0", 4)
+    t.shrink_lease("d0", 4)
     before_local = len(t._free_local)
     before_host = len(t._free_host)
     with pytest.raises(MemoryError, match="all tiers full"):

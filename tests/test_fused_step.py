@@ -27,8 +27,20 @@ FAMILIES = ["qwen1.5-0.5b", "rwkv6-3b", "deepseek-v2-lite-16b",
             "jamba-v0.1-52b"]
 
 
+# Two XLA programs of different shapes may sum f32 products in a different
+# order (XLA promises no bit equality across shapes): a few dozen f32 ulps
+# at the O(1) magnitude of the smoke model's logits and page payloads.
+PROGRAM_ATOL = 1e-5
+
+
 def _rand(rng, shape, dtype):
     return jnp.asarray(rng.standard_normal(shape), jnp.float32).astype(dtype)
+
+
+def _assert_close(a, b, err_msg=""):
+    np.testing.assert_allclose(np.asarray(a, np.float32),
+                               np.asarray(b, np.float32), rtol=0,
+                               atol=PROGRAM_ATOL, err_msg=err_msg)
 
 
 # ---------------------------------------------------------------------------
@@ -102,7 +114,8 @@ def _fused_vs_per_request(arch, park_mid_step=False):
     bucket-crossing mid-page chunk (6 tokens from position 5), request 2 on
     its first chunk — executed as three per-request calls on runtime A and
     as ONE ``serve_step_paged`` call on runtime B. Logits and every
-    request-owned page must be BIT-identical."""
+    request-owned page must agree within ``PROGRAM_ATOL`` (the fused call is
+    a program of another shape than the per-request ones)."""
     cfg = smoke_config(get_config(arch))
     params = api.init_params(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(7)
@@ -165,15 +178,14 @@ def _fused_vs_per_request(arch, park_mid_step=False):
         jnp.asarray(q_starts), jnp.asarray(n_reals), n_decode=n_dec,
         read_pps=kvB.pps)
     lg = np.asarray(lg)
-    np.testing.assert_array_equal(lg[0], logits["dec"])
-    np.testing.assert_array_equal(lg[2], logits[1])
-    np.testing.assert_array_equal(lg[3], logits[2])
+    _assert_close(lg[0], logits["dec"])
+    _assert_close(lg[2], logits[1])
+    _assert_close(lg[3], logits[2])
     for name in kvA.planes:
         pa, pb = kvA.planes[name], kvB.planes[name]
         for rid in (0, 1, 2):
-            np.testing.assert_array_equal(
-                np.asarray(pa.aqua.read(pa.flat(rid))),
-                np.asarray(pb.aqua.read(pb.flat(rid))), err_msg=name)
+            _assert_close(pa.aqua.read(pa.flat(rid)),
+                          pb.aqua.read(pb.flat(rid)), err_msg=name)
 
 
 @pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "deepseek-v2-lite-16b"])
@@ -190,8 +202,9 @@ def test_fused_step_bit_identical_to_per_request_state_families(arch):
 @pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "jamba-v0.1-52b"])
 def test_fused_step_bit_identical_after_mid_step_park_roundtrip(arch):
     """A park/restore round trip between the per-request prefix and the
-    fused step (every plane's pages flip tiers and come back) must not
-    perturb a single bit of the fused step's logits or written pages."""
+    fused step (every plane's pages flip tiers and come back, bit for bit)
+    leaves the fused step's logits and written pages within
+    ``PROGRAM_ATOL`` of the per-request path."""
     _fused_vs_per_request(arch, park_mid_step=True)
 
 

@@ -26,7 +26,7 @@ pytestmark = pytest.mark.skipif(
 
 
 def _tensor(dom, *, slots=8, page=(4, 6)):
-    a = AquaTensor(n_logical=32, page_shape=page, local_slots=slots,
+    a = AquaTensor(page_shape=page, local_slots=slots,
                    host_slots=slots, dtype=jnp.float32,
                    meter=TransferMeter(), mesh=dom)
     a.add_remote_lease("d0", slots)
@@ -88,7 +88,7 @@ def test_one_collective_per_tier_donor_leg():
 
 def test_two_donors_one_collective_each():
     dom = MeshTierDomain()
-    a = AquaTensor(n_logical=32, page_shape=(4, 6), local_slots=8,
+    a = AquaTensor(page_shape=(4, 6), local_slots=8,
                    host_slots=8, dtype=jnp.float32, meter=TransferMeter(),
                    mesh=dom)
     a.add_remote_lease("d0", 4)
@@ -106,6 +106,49 @@ def test_two_donors_one_collective_each():
     np.testing.assert_array_equal(
         np.asarray(a.read(lps)),
         np.full((6,) + a.page_shape, 2.0, np.float32))
+
+
+def test_spilled_remote_leg_is_a_host_message():
+    """A REMOTE-bound park that overflows its lease spills the rest to
+    HOST. The spilled leg never touches the fabric: it is priced as a host
+    message and issues no collective."""
+    dom = MeshTierDomain()
+    a = AquaTensor(page_shape=(4, 6), local_slots=8, host_slots=8,
+                   dtype=jnp.float32, meter=TransferMeter(), mesh=dom)
+    a.add_remote_lease("d0", 2)
+    lps = a.allocate(4)
+    a.write_local(lps, jnp.ones((4,) + a.page_shape, jnp.float32))
+    c0, f0, h0 = dom.collectives, a.meter.messages_fabric, a.meter.messages_host
+    a.offload(lps, prefer=REMOTE)
+    assert sorted(a.page_table[lps, 0].tolist()) == [REMOTE, REMOTE, HOST, HOST]
+    assert dom.collectives - c0 == 1
+    assert a.meter.messages_fabric - f0 == 1
+    assert a.meter.messages_host - h0 == 1
+
+
+def test_engine_collectives_equal_fabric_messages_every_step():
+    """Serving under CFS with contexts parked in peer HBM: each step's
+    collectives equal its priced fabric messages, also in the steps where a
+    park's allocation demotes cached prefix pages (a demotion is its own
+    migration, never folded into the park's message)."""
+    from repro.launch.serve import build_engine
+    mesh = MeshTierDomain()
+    eng = build_engine("qwen1.5-0.5b", smoke=True, mesh=mesh, max_running=2,
+                       max_seq=128, slice_tokens=3, step_tokens=32)
+    rng = np.random.default_rng(0)
+    for n in (23, 37, 41, 55, 60, 71, 80, 90):
+        eng.submit(list(map(int, rng.integers(0, eng.cfg.vocab_size, n))), 8)
+    meter = eng.pager.meter
+    for _ in range(500):
+        if not (eng.waiting or eng.running):
+            break
+        c0, m0 = mesh.collectives, meter.messages_fabric
+        eng.step()
+        assert (mesh.collectives - c0 == meter.messages_fabric - m0), \
+            f"step {eng.metrics.steps}"
+    assert len(eng.finished) == 8
+    assert eng.metrics.preemptions > 0
+    assert eng.kv.stats()["cache"]["demotions"] > 0
 
 
 # ---------------------------------------------------------------------------
@@ -235,7 +278,7 @@ def test_donor_re_lease_reuses_bookkeeping_index():
     """An evicted donor that re-leases must reuse its ``_donors`` entry: a
     duplicate append would leave stale donor_idx values resolving to the
     new pool and split one physical donor across two identities."""
-    a = AquaTensor(n_logical=16, page_shape=(2, 4), local_slots=8,
+    a = AquaTensor(page_shape=(2, 4), local_slots=8,
                    host_slots=16, dtype=jnp.float32, meter=TransferMeter())
     a.add_remote_lease("d0", 4)
     lps = a.allocate(3)

@@ -94,7 +94,7 @@ def test_append_then_attend_equals_contiguous():
 # AquaTensor: batched block tables, partial tails, tier exhaustion
 # ---------------------------------------------------------------------------
 def test_block_tables_batched_query_and_padding():
-    t = AquaTensor(n_logical=32, page_shape=(4,), local_slots=16,
+    t = AquaTensor(page_shape=(4,), local_slots=16,
                    host_slots=8, dtype=jnp.float32)
     a = t.allocate(3)
     b = t.allocate(2)
@@ -109,7 +109,7 @@ def test_block_tables_batched_query_and_padding():
 
 
 def test_partial_tail_pages_metered_at_fill():
-    t = AquaTensor(n_logical=16, page_shape=(8,), local_slots=8,
+    t = AquaTensor(page_shape=(8,), local_slots=8,
                    host_slots=16, dtype=jnp.bfloat16)
     lps = t.allocate(4)
     t.write_local(lps, jnp.ones((4, 8), jnp.bfloat16))
@@ -122,7 +122,7 @@ def test_partial_tail_pages_metered_at_fill():
 def test_move_to_full_tier_raises_memoryerror_not_indexerror():
     """Regression: host-tier exhaustion during migration used to surface as a
     bare IndexError from list.pop on the empty free list."""
-    t = AquaTensor(n_logical=16, page_shape=(4,), local_slots=8, host_slots=2,
+    t = AquaTensor(page_shape=(4,), local_slots=8, host_slots=2,
                    dtype=jnp.float32, name="kvtest")
     lps = t.allocate(4)
     t.write_local(lps, jnp.ones((4, 4), jnp.float32))
@@ -131,7 +131,7 @@ def test_move_to_full_tier_raises_memoryerror_not_indexerror():
 
 
 def test_evict_remote_onto_full_host_raises_memoryerror():
-    t = AquaTensor(n_logical=16, page_shape=(4,), local_slots=8, host_slots=1,
+    t = AquaTensor(page_shape=(4,), local_slots=8, host_slots=1,
                    dtype=jnp.float32, name="kvtest")
     t.add_remote_lease("d0", 8)
     lps = t.allocate(3)
@@ -139,6 +139,23 @@ def test_evict_remote_onto_full_host_raises_memoryerror():
     t.offload(lps, prefer=REMOTE)
     with pytest.raises(MemoryError, match="kvtest.*host"):
         t.evict_remote("d0")
+
+
+def test_logical_ids_cover_every_physical_slot_and_lease():
+    """Every physical slot — LOCAL, host, and each remote lease added later
+    — can hold a page: logical ids never run out first. At full width one
+    1024-token qwen1.5-0.5b request takes 24 x 128 kv pages, so a fixed id
+    count would run dry long before the slots do."""
+    cfg = smoke_config(get_config(ARCH))
+    kv = PagedStateRuntime(cfg, max_seq=64, local_pages=9, host_pages=16400,
+                           max_running=2)
+    a = kv.planes["kv"].aqua
+    assert len(a.allocate(8 + 16400)) == 16408    # LOCAL (bar scratch), HOST
+    kv.add_remote_lease("d0", 64 * a.page_bytes)
+    assert a.remote_capacity["d0"] == 64
+    assert (a.page_table[a.allocate(64), 0] == REMOTE).all()
+    with pytest.raises(MemoryError):
+        a.allocate(1)
 
 
 # ---------------------------------------------------------------------------

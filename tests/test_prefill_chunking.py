@@ -117,8 +117,11 @@ def test_bucket_tokens_ladder():
 # ---------------------------------------------------------------------------
 def test_chunked_prefill_bit_identical_across_chunk_sizes():
     """Whole-prompt prefill is the single-chunk case; every split — including
-    chunk boundaries mid-page — yields BIT-identical logits, because each
-    token's page-sequence softmax reduction order is split-invariant."""
+    chunk boundaries mid-page — yields the same logits: each token's
+    page-sequence softmax reduction order is split-invariant. Chunks of
+    different lengths are programs of different shapes, whose f32 sums XLA
+    may order differently, so they agree within a few dozen f32 ulps at the
+    logits' O(1) magnitude rather than bit for bit."""
     cfg = smoke_config(get_config(ARCH))
     params = api.init_params(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(2)
@@ -142,7 +145,8 @@ def test_chunked_prefill_bit_identical_across_chunk_sizes():
 
     whole = last_logits([17])
     for splits in ([5, 12], [8, 4, 5], [12, 5], [16, 1]):
-        np.testing.assert_array_equal(last_logits(splits), whole), splits
+        np.testing.assert_allclose(last_logits(splits), whole, rtol=0,
+                                   atol=1e-5, err_msg=str(splits))
 
 
 def test_engine_chunked_tokens_match_greedy_incl_mid_page_chunks():

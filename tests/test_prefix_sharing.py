@@ -146,7 +146,7 @@ def test_full_match_copy_on_write_isolates_the_sharer(qwen):
 def test_refcounted_free_keeps_shared_pages_alive():
     """AquaTensor refcounts: freeing one referencer neither releases the
     physical slot nor touches the payload; the last free does both."""
-    t = AquaTensor(n_logical=16, page_shape=(4,), local_slots=8, host_slots=4,
+    t = AquaTensor(page_shape=(4,), local_slots=8, host_slots=4,
                    dtype=jnp.float32, name="shared")
     lps = t.allocate(2)
     t.write_local(lps, jnp.arange(8, dtype=jnp.float32).reshape(2, 4))

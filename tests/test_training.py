@@ -139,9 +139,9 @@ def test_compressed_psum_matches_mean():
     mesh = Mesh(np.array(devs[:1]), ("dp",))
     g = jnp.asarray(np.random.default_rng(0).standard_normal((1, 32)), jnp.float32)
     err = jnp.zeros((1, 32))
-    from repro.distributed.sharding import shard_map_compat
-    f = shard_map_compat(lambda g, e: compressed_psum(g[0], e[0], "dp"),
-                         mesh, (P("dp"), P("dp")), P(), check=False)
+    f = jax.shard_map(lambda g, e: compressed_psum(g[0], e[0], "dp"),
+                      mesh=mesh, in_specs=(P("dp"), P("dp")), out_specs=P(),
+                      check_vma=False)
     out, _ = f(g, err)
     np.testing.assert_allclose(np.asarray(out), np.asarray(g[0]),
                                atol=float(jnp.abs(g).max()) / 100)
