@@ -220,7 +220,7 @@ def fused_step_text(eng) -> str:
     Tc = bucket_tokens(eng.step_tokens)
     zeros = jnp.zeros((R,), jnp.int32)
     step = lm._serve_step_jit(eng.cfg, eng.paged_impl, eng.kv.pps,
-                              eng.max_running)
+                              eng.max_running, "mixed")
     return step.lower(eng.params, jnp.zeros((R, Tc), jnp.int32),
                       eng.kv.pools, eng.kv.block_tables([None] * R,
                                                         pad_to=eng._pps_pad),

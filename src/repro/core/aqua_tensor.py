@@ -54,6 +54,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.errors import (AquaError, LeaseRevokedError, PageLossError,
                                TransferFaultError)
@@ -764,13 +765,17 @@ class AquaTensor:
             # pages mapped to free-listed slots, a double-free on their
             # eventual release.
             if src_tier == LOCAL:
-                staging = kv_ops.gather_pages(self.local_pool, jnp.asarray(slots))
+                with TraceAnnotation("aqua.tier.gather", pages=len(slots)):
+                    staging = kv_ops.gather_pages(self.local_pool,
+                                                  jnp.asarray(slots))
             elif src_tier == REMOTE:
                 donor_name = self._donors[src_donor]
-                staging = self._remote_gather(donor_name, slots)
+                with TraceAnnotation("aqua.tier.gather", pages=len(slots)):
+                    staging = self._remote_gather(donor_name, slots)
             else:
                 self._leg_guard(HOST, None, len(slots))
-                staging = jnp.asarray(self.host_pool[slots])
+                with TraceAnnotation("aqua.tier.h2d", pages=len(slots)):
+                    staging = jnp.asarray(self.host_pool[slots])
             # valid payload only: a partial tail page moves (and is priced
             # as) its live rows, not the whole page buffer
             fills = self.page_fill[group] * self.page_bytes   # per-page bytes
@@ -808,9 +813,11 @@ class AquaTensor:
                                                 len(group))
                                  for _ in group]
                     popped += [(self._free_local, s) for s in dst_slots]
-                    self.local_pool = kv_ops.scatter_pages(
-                        self.local_pool, staging,
-                        jnp.asarray(dst_slots, jnp.int32))
+                    with TraceAnnotation("aqua.tier.scatter",
+                                         pages=len(group)):
+                        self.local_pool = kv_ops.scatter_pages(
+                            self.local_pool, staging,
+                            jnp.asarray(dst_slots, jnp.int32))
                     new_rows = [(LOCAL, s, -1) for s in dst_slots]
                     meter(0, len(group), LOCAL, None)
                 elif dst_tier == REMOTE:
@@ -824,8 +831,9 @@ class AquaTensor:
                             continue
                         dst_slots = [free.pop() for _ in range(take)]
                         popped += [(free, s) for s in dst_slots]
-                        self._remote_scatter(d, dst_slots,
-                                             staging[placed:placed + take])
+                        with TraceAnnotation("aqua.tier.scatter", pages=take):
+                            self._remote_scatter(
+                                d, dst_slots, staging[placed:placed + take])
                         new_rows += [(REMOTE, s, di) for s in dst_slots]
                         meter(placed, placed + take, REMOTE, d)
                         placed += take
@@ -837,7 +845,9 @@ class AquaTensor:
                                                     need)
                                      for _ in range(need)]
                         popped += [(self._free_host, s) for s in dst_slots]
-                        self.host_pool[np.asarray(dst_slots)] = np.asarray(rest)
+                        with TraceAnnotation("aqua.tier.d2h", pages=need):
+                            self.host_pool[np.asarray(dst_slots)] = \
+                                np.asarray(rest)
                         new_rows += [(HOST, s, -1) for s in dst_slots]
                         meter(placed, len(group), HOST, None)
                 else:
@@ -846,7 +856,9 @@ class AquaTensor:
                                                 len(group))
                                  for _ in group]
                     popped += [(self._free_host, s) for s in dst_slots]
-                    self.host_pool[np.asarray(dst_slots)] = np.asarray(staging)
+                    with TraceAnnotation("aqua.tier.d2h", pages=len(group)):
+                        self.host_pool[np.asarray(dst_slots)] = \
+                            np.asarray(staging)
                     new_rows = [(HOST, s, -1) for s in dst_slots]
                     meter(0, len(group), HOST, None)
             except (MemoryError, AquaError):

@@ -19,11 +19,13 @@ legs lower to collectives:
                            ONE ``ppermute`` back to the serving shard
 
 Both legs run inside a single ``shard_map`` program per (bucket, pool-shape)
-key, so each (plane, tier, donor) leg of a tier flip is exactly one
-collective message on the wire — the physical counterpart of the
-``TransferMeter`` coalescing invariant (``collectives`` counts them, tests
-assert one per leg). Page counts pad to power-of-two buckets so the jit
-cache stays flat however many pages a request parks.
+key, named ``aqua_mesh_push`` / ``aqua_mesh_pull`` (host spans
+``aqua.mesh.push`` / ``aqua.mesh.pull`` around each leg), so each (plane,
+tier, donor) leg of a tier flip is exactly one collective message on the
+wire — the physical counterpart of the ``TransferMeter`` coalescing
+invariant (``collectives`` counts them, tests assert one per leg). Page
+counts pad to power-of-two buckets so the jit cache stays flat however many
+pages a request parks.
 
 Every warm leg is wall-clocked (``block_until_ready``; the first call per
 compiled key is compile time and is skipped), and the samples feed
@@ -43,6 +45,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.errors import LeaseRevokedError
@@ -160,19 +163,20 @@ class MeshTierDomain:
         self._guard_donor(donor, "push")
         dst = self.donor_device(donor)
         n = len(slots)
-        S = pool.shape[1] - 1
-        page_shape = tuple(pool.shape[2:])
-        dtype = pool.dtype
-        b = _bucket(n)
-        slots = np.asarray(slots, np.int32)
-        data = jnp.asarray(data, dtype)
-        if b > n:                             # pad to the bucket: scratch row
-            slots = np.concatenate([slots, np.full(b - n, S, np.int32)])
-            data = jnp.concatenate(
-                [data, jnp.zeros((b - n,) + page_shape, dtype)], axis=0)
-        fn, key = self._push_fn(dst, b, S, page_shape, str(dtype))
-        stage = self._stage(data, b, page_shape, dtype)
-        out, dt = self._timed(fn, pool, stage, jnp.asarray(slots))
+        with TraceAnnotation("aqua.mesh.push", donor=donor, pages=n):
+            S = pool.shape[1] - 1
+            page_shape = tuple(pool.shape[2:])
+            dtype = pool.dtype
+            b = _bucket(n)
+            slots = np.asarray(slots, np.int32)
+            data = jnp.asarray(data, dtype)
+            if b > n:                         # pad to the bucket: scratch row
+                slots = np.concatenate([slots, np.full(b - n, S, np.int32)])
+                data = jnp.concatenate(
+                    [data, jnp.zeros((b - n,) + page_shape, dtype)], axis=0)
+            fn, key = self._push_fn(dst, b, S, page_shape, str(dtype))
+            stage = self._stage(data, b, page_shape, dtype)
+            out, dt = self._timed(fn, pool, stage, jnp.asarray(slots))
         self._account(key, b * int(np.prod(page_shape)) * dtype.itemsize, dt)
         return out
 
@@ -184,14 +188,15 @@ class MeshTierDomain:
         self._guard_donor(donor, "pull")
         src = self.donor_device(donor)
         n = len(slots)
-        S = pool.shape[1] - 1
-        page_shape = tuple(pool.shape[2:])
-        b = _bucket(n)
-        slots = np.asarray(slots, np.int32)
-        if b > n:                             # padded gathers are discarded
-            slots = np.concatenate([slots, np.zeros(b - n, np.int32)])
-        fn, key = self._pull_fn(src, b, S, page_shape, str(pool.dtype))
-        out, dt = self._timed(fn, pool, jnp.asarray(slots))
+        with TraceAnnotation("aqua.mesh.pull", donor=donor, pages=n):
+            S = pool.shape[1] - 1
+            page_shape = tuple(pool.shape[2:])
+            b = _bucket(n)
+            slots = np.asarray(slots, np.int32)
+            if b > n:                         # padded gathers are discarded
+                slots = np.concatenate([slots, np.zeros(b - n, np.int32)])
+            fn, key = self._pull_fn(src, b, S, page_shape, str(pool.dtype))
+            out, dt = self._timed(fn, pool, jnp.asarray(slots))
         self._account(key, b * int(np.prod(page_shape)) * pool.dtype.itemsize,
                       dt)
         for shard in out.addressable_shards:
@@ -220,7 +225,7 @@ class MeshTierDomain:
         if fn is None:
             axis = self.axis
 
-            def step(pool_s, stage_s, slots):
+            def aqua_mesh_push(pool_s, stage_s, slots):
                 # pool_s (1, S+1, *page), stage_s (1, bucket, *page): this
                 # device's shards; slots replicated. One collective moves the
                 # staged batch serving -> donor; only the donor keeps the
@@ -231,7 +236,8 @@ class MeshTierDomain:
                 return jnp.where(keep, upd, pool_s[0])[None]
 
             fn = jax.jit(jax.shard_map(
-                step, mesh=self.mesh, in_specs=(P(axis), P(axis), P()),
+                aqua_mesh_push, mesh=self.mesh,
+                in_specs=(P(axis), P(axis), P()),
                 out_specs=P(axis), check_vma=False))
             self._push_cache[key] = fn
         return fn, key
@@ -243,7 +249,7 @@ class MeshTierDomain:
         if fn is None:
             axis = self.axis
 
-            def step(pool_s, slots):
+            def aqua_mesh_pull(pool_s, slots):
                 # gather is cheap on every shard; only the donor's rows are
                 # real, and one collective moves them donor -> serving
                 # (non-addressed shards receive zeros per ppermute semantics)
@@ -251,7 +257,7 @@ class MeshTierDomain:
                 return jax.lax.ppermute(stage[None], axis, [(src, 0)])
 
             fn = jax.jit(jax.shard_map(
-                step, mesh=self.mesh, in_specs=(P(axis), P()),
+                aqua_mesh_pull, mesh=self.mesh, in_specs=(P(axis), P()),
                 out_specs=P(axis), check_vma=False))
             self._pull_cache[key] = fn
         return fn, key

@@ -23,18 +23,25 @@ def _canon(pool):
     return pool.reshape(P, n // d, d), payload
 
 
-@jax.jit
-def gather_pages(pool, page_ids):
+# The programs are named (XLA modules ``jit_aqua_gather_pages`` and
+# ``jit_aqua_scatter_pages``) so a profile finds every tier move by name.
+def aqua_gather_pages(pool, page_ids):
     """Coalesce scattered pages into one contiguous staging buffer."""
-    p3, payload = _canon(pool)
-    out = _gather(p3, page_ids, interpret=_on_cpu())
-    return out.reshape((page_ids.shape[0],) + tuple(payload))
+    with jax.named_scope("aqua_gather_pages"):
+        p3, payload = _canon(pool)
+        out = _gather(p3, page_ids, interpret=_on_cpu())
+        return out.reshape((page_ids.shape[0],) + tuple(payload))
 
 
-@jax.jit
-def scatter_pages(pool, staging, page_ids):
-    """Scatter a staging buffer back into the page pool (in-place on TPU)."""
-    p3, payload = _canon(pool)
-    s3 = staging.reshape((staging.shape[0],) + p3.shape[1:])
-    out = _scatter(p3, s3, page_ids, interpret=_on_cpu())
-    return out.reshape(pool.shape)
+def aqua_scatter_pages(pool, staging, page_ids):
+    """Scatter a staging buffer back into the page pool. The jit does not
+    donate the pool, so XLA copies it before the kernel writes in place."""
+    with jax.named_scope("aqua_scatter_pages"):
+        p3, payload = _canon(pool)
+        s3 = staging.reshape((staging.shape[0],) + p3.shape[1:])
+        out = _scatter(p3, s3, page_ids, interpret=_on_cpu())
+        return out.reshape(pool.shape)
+
+
+gather_pages = jax.jit(aqua_gather_pages)
+scatter_pages = jax.jit(aqua_scatter_pages)

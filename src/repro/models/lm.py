@@ -807,15 +807,32 @@ def serve_step_paged(params, cfg: ModelConfig, tokens, pools, block_tables,
     return logits, pools
 
 
+def step_kind(n_decode: int, chunk_tokens: int) -> str:
+    """Which fused-step program a packed ``(rows, chunk_tokens)`` batch
+    runs: ``chunk`` (no decode lanes), ``decode`` (decode lanes only,
+    ``Tc == 1``) or ``mixed`` (decode lanes plus a chunk region)."""
+    if n_decode == 0:
+        return "chunk"
+    return "decode" if chunk_tokens == 1 else "mixed"
+
+
 @functools.lru_cache(maxsize=None)
 def _serve_step_jit(cfg: ModelConfig, impl: str, read_pps: Optional[int],
-                    n_decode: int):
-    """One compiled program per (config, impl, n_decode, shape bucket)."""
-    return jax.jit(lambda params, tokens, pools, bt, q_starts, n_reals, pre:
-                   serve_step_paged(params, cfg, tokens, pools, bt, q_starts,
+                    n_decode: int, kind: str):
+    """One compiled program per (config, impl, n_decode, shape bucket),
+    named ``aqua_step_<kind>`` (its XLA module is ``jit_aqua_step_<kind>``)
+    so a profile tells the three step programs apart."""
+    name = f"aqua_step_{kind}"
+
+    def program(params, tokens, pools, bt, q_starts, n_reals, pre):
+        with jax.named_scope(name):
+            return serve_step_paged(params, cfg, tokens, pools, bt, q_starts,
                                     n_reals, n_decode=n_decode,
                                     prefix_embeds=pre, read_pps=read_pps,
-                                    impl=impl))
+                                    impl=impl)
+
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program)
 
 
 def serve_step_paged_jit(params, cfg: ModelConfig, tokens, pools,
@@ -825,7 +842,8 @@ def serve_step_paged_jit(params, cfg: ModelConfig, tokens, pools,
     """Jit'd fused step: callers pass bucket-padded row counts and chunk
     lengths, so the trace count is bounded by the (rows x tokens) bucket
     ladder — flat in the number of admitted requests."""
-    return _serve_step_jit(cfg, impl, read_pps, n_decode)(
+    kind = step_kind(n_decode, tokens.shape[1])
+    return _serve_step_jit(cfg, impl, read_pps, n_decode, kind)(
         params, tokens, pools, block_tables, q_starts, n_reals,
         prefix_embeds)
 

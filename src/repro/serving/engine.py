@@ -68,6 +68,7 @@ from typing import Dict, List, Optional, Sequence
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.aqua_tensor import HOST, REMOTE
@@ -79,7 +80,7 @@ from repro.core.errors import (CancelledError, EngineCrashError,
 from repro.core.faults import InvariantAuditor
 from repro.core.perfmodel import (HardwareProfile, ModelCost, TPU_V5E,
                                   overlapped_transfer_time)
-from repro.models import api
+from repro.models import api, lm
 from repro.serving.kv_cache import PagedStateRuntime
 from repro.serving.scheduler import (CFSScheduler, Decision, FCFSScheduler,
                                      ReqState, bucket_tokens, fairness_spread,
@@ -109,11 +110,7 @@ class EngineMetrics:
     fairness_trace: List[int] = field(default_factory=list)
     step_times: List[float] = field(default_factory=list)
     prefill_tokens_trace: List[int] = field(default_factory=list)
-    # kernel launches per step: fused (what the engine issues — one call,
-    # ~n_layers launches) vs the per-request baseline it replaced (one call
-    # per chunk row + one for decode, each ~n_layers launches)
-    launch_trace: List[int] = field(default_factory=list)
-    baseline_launch_trace: List[int] = field(default_factory=list)
+    fused_calls: int = 0                  # serve_step_paged dispatches
     # fault-tolerance accounting (zero on a fault-free run): transfer-leg
     # retries absorbed by bounded backoff, donor losses / lease shrinks
     # applied, pages live-migrated off shrinking donors, and the requests
@@ -704,7 +701,8 @@ class ServingEngine:
         self._draining = True
         n = 0
         for r in list(self.running):
-            self.kv.park(r.rid, r.resident_tokens, prefer=HOST)
+            self.kv.park(r.rid, r.resident_tokens, prefer=HOST,
+                         cause="drain")
             r.parked = True
             self._free_slot(r)
             self.running.remove(r)
@@ -714,7 +712,8 @@ class ServingEngine:
         for r in self.waiting:
             # prefetched restores / speculated chunks left pages active
             if r.rid in self.kv._active:
-                self.kv.park(r.rid, r.resident_tokens, prefer=HOST)
+                self.kv.park(r.rid, r.resident_tokens, prefer=HOST,
+                             cause="drain")
                 r.parked = True
                 n += 1
         m.drained += n
@@ -852,8 +851,14 @@ class ServingEngine:
         call; (5) finished requests retire (pages released — shared prefix
         pages survive while any sharer lives); (6) next step's restores are
         prefetched, priced as hidden up to this step's compute time.
-        Metrics (TTFT/RCT on the simulated clock, step times, launches per
-        step, fairness spread) accrue on ``self.metrics``.
+        Metrics (TTFT/RCT on the simulated clock, step times, fused calls,
+        fairness spread) accrue on ``self.metrics``.
+
+        Each step is an ``aqua.step`` profiler span (its ``step_num`` and
+        ``kind``: decode, mixed, chunk or idle) holding ``aqua.step.plan``,
+        ``.place``, ``.pack``, ``.dispatch``, ``.readback``, ``.retire`` and
+        ``.prefetch`` spans; a span costs about a microsecond when no
+        profiler runs.
 
         Raises:
             SchedulingInvariantError: the planned run set needs more batch
@@ -862,6 +867,11 @@ class ServingEngine:
                 the target tier full (the page-budget-aware schedulers are
                 designed to keep plans below this point).
         """
+        with StepTraceAnnotation("aqua.step",
+                                 step_num=self.metrics.steps) as span:
+            self._step(span)
+
+    def _step(self, span):
         m = self.metrics
         if self.coord is not None and m.steps % self.respond_every == 0:
             self._respond()
@@ -869,6 +879,70 @@ class ServingEngine:
                       else 0.0)
         self._shed_expired()
 
+        with TraceAnnotation("aqua.step.plan"):
+            decision, lanes, pending, chunks, flops_slack = self._plan()
+
+        with TraceAnnotation("aqua.step.place"):
+            transfer_time = self._place(decision)
+
+        self.running = [r for r in decision.run if r.slot is not None]
+        self.waiting = [r for r in self.waiting + decision.preempt
+                        if r.slot is None and not r.done]
+
+        # all the step's model work — decode lanes + prompt chunks (+ a
+        # speculative chunk-ahead when the budget has slack) — in ONE call
+        live = [r for r in self.running if not r.done and r.prefilled]
+        chunk_plan = [(r, n) for r, n in zip(pending, chunks)
+                      if n > 0 and r.slot is not None]
+        with TraceAnnotation("aqua.step.plan"):
+            specs = self._pick_speculative(decision, len(lanes), chunks,
+                                           len(chunk_plan), flops_slack)
+        kind, compute_time, fused_transfer = self._fused_step(
+            live, chunk_plan, specs)
+        span.set_metadata(kind=kind)
+        step_time = compute_time + transfer_time + fused_transfer + fault_time
+
+        # retire bookkeeping first: freed slots/pages raise the odds the
+        # prefetch below fits (times are stamped after the prefetch)
+        retired = []
+        with TraceAnnotation("aqua.step.retire"):
+            for r in list(self.running):
+                if r.done:
+                    self.running.remove(r)
+                    self._retire(r, "finished")
+                    retired.append(r)
+
+        if self.watchdog_steps is not None:
+            self._watchdog()
+
+        with TraceAnnotation("aqua.step.prefetch"):
+            step_time += self._prefetch_restores(compute_time)
+
+        # TTFT: one accounting for prefill- and decode-produced first tokens —
+        # the time the step COMPLETES, including everything accrued in it
+        # (the visible excess of a prefetched restore included)
+        for r in self.running + retired:
+            if r.generated and r.rid not in m.ttft:
+                r.ttft_step = m.steps
+                m.ttft[r.rid] = m.sim_time + step_time - r.arrival
+        for r in retired:
+            m.rct[r.rid] = m.sim_time + step_time - r.arrival
+
+        m.sim_time += step_time
+        m.steps += 1
+        m.step_times.append(step_time)
+        m.fairness_trace.append(
+            fairness_spread(self.waiting + self.running))
+        m.leg_retries = (self.pager.meter.retries_fabric
+                         + self.pager.meter.retries_host)
+        if self.auditor is not None:
+            self.auditor.audit(self.kv, engine=self)
+
+    def _plan(self) -> tuple:
+        """Admission, the scheduler's plan and the step's token budget:
+        ``(decision, decode lanes, pending prefills, their chunks, the
+        decode launch's FLOPs slack)``."""
+        m = self.metrics
         # admission gate: the scheduler only ever sees the eligible subset
         # of the queue — deferred requests stay waiting (degrade-to-queue)
         # until completions reopen the stability region. While draining,
@@ -906,57 +980,7 @@ class ServingEngine:
             self.step_tokens, len(lanes),
             [r.prompt_positions - r.prefill_pos for r in pending],
             flops_slack=flops_slack)
-
-        transfer_time = self._place(decision)
-
-        self.running = [r for r in decision.run if r.slot is not None]
-        self.waiting = [r for r in self.waiting + decision.preempt
-                        if r.slot is None and not r.done]
-
-        # all the step's model work — decode lanes + prompt chunks (+ a
-        # speculative chunk-ahead when the budget has slack) — in ONE call
-        live = [r for r in self.running if not r.done and r.prefilled]
-        chunk_plan = [(r, n) for r, n in zip(pending, chunks)
-                      if n > 0 and r.slot is not None]
-        specs = self._pick_speculative(decision, len(lanes), chunks,
-                                       len(chunk_plan), flops_slack)
-        compute_time, fused_transfer = self._fused_step(live, chunk_plan,
-                                                        specs)
-        step_time = compute_time + transfer_time + fused_transfer + fault_time
-
-        # retire bookkeeping first: freed slots/pages raise the odds the
-        # prefetch below fits (times are stamped after the prefetch)
-        retired = []
-        for r in list(self.running):
-            if r.done:
-                self.running.remove(r)
-                self._retire(r, "finished")
-                retired.append(r)
-
-        if self.watchdog_steps is not None:
-            self._watchdog()
-
-        step_time += self._prefetch_restores(compute_time)
-
-        # TTFT: one accounting for prefill- and decode-produced first tokens —
-        # the time the step COMPLETES, including everything accrued in it
-        # (the visible excess of a prefetched restore included)
-        for r in self.running + retired:
-            if r.generated and r.rid not in m.ttft:
-                r.ttft_step = m.steps
-                m.ttft[r.rid] = m.sim_time + step_time - r.arrival
-        for r in retired:
-            m.rct[r.rid] = m.sim_time + step_time - r.arrival
-
-        m.sim_time += step_time
-        m.steps += 1
-        m.step_times.append(step_time)
-        m.fairness_trace.append(
-            fairness_spread(self.waiting + self.running))
-        m.leg_retries = (self.pager.meter.retries_fabric
-                         + self.pager.meter.retries_host)
-        if self.auditor is not None:
-            self.auditor.audit(self.kv, engine=self)
+        return decision, lanes, pending, chunks, flops_slack
 
     # ------------------------------------------------------------------
     # placement: park preempted requests, slot + restore the scheduled set
@@ -975,13 +999,14 @@ class ServingEngine:
                 if (r.parked is None and r.slot is None and not r.done
                         and r.rid not in run_ids):
                     self.kv.park(r.rid, r.resident_tokens,
-                                 prefer=self.offload_tier)
+                                 prefer=self.offload_tier, cause="mispredict")
                     r.parked = True
             self._prefetched = []
         for r in decision.preempt:
             # only r.resident_tokens of context exist in the pools: the
             # newest generated token's state lands at its next decode step
-            self.kv.park(r.rid, r.resident_tokens, prefer=self.offload_tier)
+            self.kv.park(r.rid, r.resident_tokens, prefer=self.offload_tier,
+                         cause="preempt")
             r.parked = True
             self._free_slot(r)
             m.preemptions += 1
@@ -995,7 +1020,8 @@ class ServingEngine:
                     f"{self.max_running}) — scheduler exceeded the slot cap")
             r.slot = self._free_slots.pop()
             if r.parked:
-                self.kv.restore(r.rid)       # ensure_local: coalesced page-in
+                # ensure_local: coalesced page-in
+                self.kv.restore(r.rid, cause="admit")
                 r.parked = None
                 m.restores += 1
         return self.pager.meter.sim_time - t_before
@@ -1018,7 +1044,7 @@ class ServingEngine:
         t_before = self.pager.meter.sim_time
         for r in nxt.run:
             if r.parked and self.kv.can_restore(r.rid):
-                self.kv.restore(r.rid)
+                self.kv.restore(r.rid, cause="prefetch")
                 r.parked = None
                 m.restores += 1
                 m.prefetched_restores += 1
@@ -1098,146 +1124,157 @@ class ServingEngine:
         any resident request decodes; idle lanes point at scratch), the
         following rows one prompt chunk each — the run set's fair-share
         chunks plus the speculative chunk-ahead grants — bucket-padded in
-        both axes. Returns ``(compute_time, metered_transfer_time)`` on
-        the analytic clock, including the O(1) per-step launch overhead
-        (``ModelCost.launch_time``)."""
+        both axes. Returns the program's kind (``lm.step_kind``, or
+        ``idle`` when nothing runs) and ``(compute_time,
+        metered_transfer_time)`` on the analytic clock, including the O(1)
+        per-step launch overhead (``ModelCost.launch_time``)."""
         m = self.metrics
         rows_chunk = list(chunk_plan) + list(specs)
         spec_rids = {r.rid for r, _ in specs}
         if not live and not rows_chunk:
             m.prefill_tokens_trace.append(0)
-            m.launch_trace.append(0)
-            m.baseline_launch_trace.append(0)
-            return 0.0, 0.0
-        t_before = self.pager.meter.sim_time
-        n_dec = self.max_running if live else 0
-        # packed shapes: with a step budget, the chunk region is FIXED at
-        # (max_running + 1 rows) x (budget bucket) whenever any chunk runs,
-        # so the jit cache is provably flat in the number of admitted
-        # requests (chunk rows — run-set chunks plus speculative grants —
-        # are capped at that fixed row bucket by _pick_speculative);
-        # the all-decode steady state stays at Tc = 1 with no chunk
-        # region. Unbudgeted (step_tokens=None) chunks are whole prompts,
-        # so their shapes ride the prompt-length bucket ladder instead.
-        if not rows_chunk:
-            Tc, Rp = 1, 0
-        elif self.step_tokens is not None:
-            Tc = bucket_tokens(self.step_tokens)
-            Rp = bucket_tokens(self.max_running + 1, lo=1)
-        else:
-            Tc = bucket_tokens(max(n for _, n in rows_chunk))
-            Rp = bucket_tokens(len(rows_chunk), lo=1)
-        R = n_dec + Rp
-        tokens = np.zeros((R, Tc), np.int32)
-        q_starts = np.zeros((R,), np.int32)
-        n_reals = np.zeros((R,), np.int32)
-        row_rids: List[Optional[int]] = [None] * R
-        prefix_rows = None
-        if self.cfg.n_prefix_embeds:
-            prefix_rows = [None] * R
-        if live:
-            n_reals[:n_dec] = 1              # idle lanes: token 0 at pos 0
-            ctx_mean = float(np.mean([r.ctx_len for r in live]))
-            for r in live:
-                # the new token's position may cross into a fresh page: grow
-                # the block tables (allocation guarantees LOCAL; parked
-                # requests were already restored in _place). A decode append
-                # landing in a still-shared page copies it first (CoW).
-                self.kv.ensure_capacity(r.rid, r.ctx_len)
-                self.kv.make_writable(r.rid, r.ctx_len - 1, r.ctx_len)
-                row_rids[r.slot] = r.rid
-                tokens[r.slot, 0] = (r.generated[-1] if r.generated
-                                     else r.prompt_tokens[-1])
-                q_starts[r.slot] = r.ctx_len - 1
-        for j, (r, n) in enumerate(rows_chunk):
-            row = n_dec + j
-            start = r.prefill_pos
-            if r.rid in spec_rids:
-                if r.parked:
-                    m.spec_restores += 1    # its prior prefix pages page in
-                try:
-                    self.kv.ensure_capacity(r.rid, start + n)
-                except MemoryError:
-                    # the run set's own same-step growth (fresh decode
-                    # pages, CoW clones) beat _pick_speculative's advisory
-                    # headroom check — speculation is opportunistic: hand
-                    # back whatever the attempt pulled LOCAL and drop this
-                    # grant and every later one (specs are the trailing
-                    # rows; the later grants haven't allocated yet)
-                    self.kv.park(r.rid, r.prefill_pos,
-                                 prefer=self.offload_tier)
-                    r.parked = True
-                    specs = specs[:j - len(chunk_plan)]
-                    rows_chunk = rows_chunk[:j]
-                    break
+            return "idle", 0.0, 0.0
+        with TraceAnnotation("aqua.step.pack"):
+            t_before = self.pager.meter.sim_time
+            n_dec = self.max_running if live else 0
+            # packed shapes: with a step budget, the chunk region is FIXED
+            # at (max_running + 1 rows) x (budget bucket) whenever any chunk
+            # runs, so the jit cache is provably flat in the number of
+            # admitted requests (chunk rows — run-set chunks plus
+            # speculative grants — are capped at that fixed row bucket by
+            # _pick_speculative); the all-decode steady state stays at
+            # Tc = 1 with no chunk region. Unbudgeted (step_tokens=None)
+            # chunks are whole prompts, so their shapes ride the
+            # prompt-length bucket ladder instead.
+            if not rows_chunk:
+                Tc, Rp = 1, 0
+            elif self.step_tokens is not None:
+                Tc = bucket_tokens(self.step_tokens)
+                Rp = bucket_tokens(self.max_running + 1, lo=1)
             else:
-                self.kv.ensure_capacity(r.rid, start + n)
-            # copy-on-write: a fully-matched prompt recomputes its final
-            # position INTO the shared tail page — clone it first
-            self.kv.make_writable(r.rid, start, start + n)
-            row_rids[row] = r.rid
-            # a VLM request's first chunks cover its prefix-embedding rows,
-            # whose token ids are dummies and whose residual rows come from
-            # prefix_embeds instead
-            idx = np.arange(n) + start - r.n_prefix
-            text = idx >= 0
-            tokens[row, :n][text] = np.asarray(r.prompt_tokens,
-                                               np.int32)[idx[text]]
-            q_starts[row] = start
-            n_reals[row] = n
+                Tc = bucket_tokens(max(n for _, n in rows_chunk))
+                Rp = bucket_tokens(len(rows_chunk), lo=1)
+            R = n_dec + Rp
+            tokens = np.zeros((R, Tc), np.int32)
+            q_starts = np.zeros((R,), np.int32)
+            n_reals = np.zeros((R,), np.int32)
+            row_rids: List[Optional[int]] = [None] * R
+            prefix_rows = None
+            if self.cfg.n_prefix_embeds:
+                prefix_rows = [None] * R
+            if live:
+                n_reals[:n_dec] = 1          # idle lanes: token 0 at pos 0
+                ctx_mean = float(np.mean([r.ctx_len for r in live]))
+                for r in live:
+                    # the new token's position may cross into a fresh page:
+                    # grow the block tables (allocation guarantees LOCAL;
+                    # parked requests were already restored in _place). A
+                    # decode append landing in a still-shared page copies it
+                    # first (CoW).
+                    self.kv.ensure_capacity(r.rid, r.ctx_len)
+                    self.kv.make_writable(r.rid, r.ctx_len - 1, r.ctx_len)
+                    row_rids[r.slot] = r.rid
+                    tokens[r.slot, 0] = (r.generated[-1] if r.generated
+                                         else r.prompt_tokens[-1])
+                    q_starts[r.slot] = r.ctx_len - 1
+            for j, (r, n) in enumerate(rows_chunk):
+                row = n_dec + j
+                start = r.prefill_pos
+                if r.rid in spec_rids:
+                    if r.parked:
+                        m.spec_restores += 1   # its prior prefix pages in
+                    try:
+                        self.kv.ensure_capacity(r.rid, start + n,
+                                                cause="spec")
+                    except MemoryError:
+                        # the run set's own same-step growth (fresh decode
+                        # pages, CoW clones) beat _pick_speculative's
+                        # advisory headroom check — speculation is
+                        # opportunistic: hand back whatever the attempt
+                        # pulled LOCAL and drop this grant and every later
+                        # one (specs are the trailing rows; the later grants
+                        # haven't allocated yet)
+                        self.kv.park(r.rid, r.prefill_pos,
+                                     prefer=self.offload_tier, cause="spec")
+                        r.parked = True
+                        specs = specs[:j - len(chunk_plan)]
+                        rows_chunk = rows_chunk[:j]
+                        break
+                else:
+                    self.kv.ensure_capacity(r.rid, start + n)
+                # copy-on-write: a fully-matched prompt recomputes its
+                # final position INTO the shared tail page — clone it first
+                self.kv.make_writable(r.rid, start, start + n)
+                row_rids[row] = r.rid
+                # a VLM request's first chunks cover its prefix-embedding
+                # rows, whose token ids are dummies and whose residual rows
+                # come from prefix_embeds instead
+                idx = np.arange(n) + start - r.n_prefix
+                text = idx >= 0
+                tokens[row, :n][text] = np.asarray(r.prompt_tokens,
+                                                   np.int32)[idx[text]]
+                q_starts[row] = start
+                n_reals[row] = n
+                if prefix_rows is not None:
+                    prefix_rows[row] = r.prefix_embeds
+            pre = None
             if prefix_rows is not None:
-                prefix_rows[row] = r.prefix_embeds
-        pre = None
-        if prefix_rows is not None:
-            P, d = self.cfg.n_prefix_embeds, self.cfg.d_model
-            zero = jnp.zeros((1, P, d), self.cfg.dtype())
-            pre = jnp.concatenate([p if p is not None else zero
-                                   for p in prefix_rows], axis=0)
-        bt = self.kv.block_tables(row_rids, pad_to=self._pps_pad)
-        logits, self.kv.pools = api.serve_step_paged(
-            self.params, self.cfg, jnp.asarray(tokens), self.kv.pools, bt,
-            jnp.asarray(q_starts), jnp.asarray(n_reals), n_decode=n_dec,
-            prefix_embeds=pre, read_pps=self.kv.pps, impl=self.paged_impl)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
+                P, d = self.cfg.n_prefix_embeds, self.cfg.d_model
+                zero = jnp.zeros((1, P, d), self.cfg.dtype())
+                pre = jnp.concatenate([p if p is not None else zero
+                                       for p in prefix_rows], axis=0)
+            bt = self.kv.block_tables(row_rids, pad_to=self._pps_pad)
+            tokens, q_starts, n_reals = (jnp.asarray(tokens),
+                                         jnp.asarray(q_starts),
+                                         jnp.asarray(n_reals))
+        kind = lm.step_kind(n_dec, Tc)
+        with TraceAnnotation("aqua.step.dispatch"):
+            logits, self.kv.pools = api.serve_step_paged(
+                self.params, self.cfg, tokens, self.kv.pools, bt, q_starts,
+                n_reals, n_decode=n_dec, prefix_embeds=pre,
+                read_pps=self.kv.pps, impl=self.paged_impl)
+        m.fused_calls += 1
+        with TraceAnnotation("aqua.step.readback"):
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))
 
         compute = 0.0
         ptoks = 0
-        for j, (r, n) in enumerate(rows_chunk):
-            r.prefill_pos += n
-            if not r.n_prefix:
-                # publish completed full prompt pages into the prefix index
-                # so later arrivals with the same prefix adopt them
-                self.kv.register_prefix(r.rid, r.prefill_pos)
-            if r.prefilled:
-                r.generated.append(int(nxt[n_dec + j]))
-            m.prefills += 1
-            ptoks += n
-        for r, n in specs:
-            m.spec_chunks += 1
-            m.spec_tokens += n
-            # hand the pages straight back: a speculative request is not
-            # in the planned run set, and LOCAL must only hold that set
-            self.kv.park(r.rid, r.prefill_pos, prefer=self.offload_tier)
-            r.parked = True
-        if live:
-            for r in live:
-                r.generated.append(int(nxt[r.slot]))
-            # mixed step: the chunk rows share the decode launch's weight
-            # pass, so their FLOPs hide under the memory-bound decode
-            # stream (ModelCost.fused_step_time) instead of paying a
-            # separate per-request launch sequence
-            compute += self.cost.fused_step_time(self.hw, len(live),
-                                                 ctx_mean,
-                                                 self.weight_bytes, ptoks)
-        elif ptoks:
-            compute += self.cost.prefill_time(self.hw, ptoks)
+        with TraceAnnotation("aqua.step.retire"):
+            for j, (r, n) in enumerate(rows_chunk):
+                r.prefill_pos += n
+                if not r.n_prefix:
+                    # publish completed full prompt pages into the prefix
+                    # index so later arrivals with the same prefix adopt them
+                    self.kv.register_prefix(r.rid, r.prefill_pos)
+                if r.prefilled:
+                    r.generated.append(int(nxt[n_dec + j]))
+                m.prefills += 1
+                ptoks += n
+            for r, n in specs:
+                m.spec_chunks += 1
+                m.spec_tokens += n
+                # hand the pages straight back: a speculative request is
+                # not in the planned run set, and LOCAL must only hold that
+                # set
+                self.kv.park(r.rid, r.prefill_pos, prefer=self.offload_tier,
+                             cause="spec")
+                r.parked = True
+            if live:
+                for r in live:
+                    r.generated.append(int(nxt[r.slot]))
+                # mixed step: the chunk rows share the decode launch's weight
+                # pass, so their FLOPs hide under the memory-bound decode
+                # stream (ModelCost.fused_step_time) instead of paying a
+                # separate per-request launch sequence
+                compute += self.cost.fused_step_time(self.hw, len(live),
+                                                     ctx_mean,
+                                                     self.weight_bytes, ptoks)
+            elif ptoks:
+                compute += self.cost.prefill_time(self.hw, ptoks)
         # ONE jitted call per step: launches stay O(1) in admitted requests
         compute += self.cost.launch_time(self.hw, 1)
         m.prefill_tokens_trace.append(ptoks)
-        m.launch_trace.append(self.cost.n_layers)
-        m.baseline_launch_trace.append(
-            (len(rows_chunk) + (1 if live else 0)) * self.cost.n_layers)
-        return compute, self.pager.meter.sim_time - t_before
+        return kind, compute, self.pager.meter.sim_time - t_before
 
     # ------------------------------------------------------------------
     def run(self, max_steps: int = 1000):

@@ -62,15 +62,17 @@ prunes their radix coverage.
 from __future__ import annotations
 
 import math
+from contextlib import nullcontext
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.aqua_tensor import (AquaTensor, HOST, LOCAL, REMOTE,
-                                    TransferMeter)
+                                    TIER_NAMES, TransferMeter)
 from repro.core.errors import LeaseRevokedError
 
 
@@ -334,15 +336,25 @@ class PagedStateRuntime:
         else:
             plane.pin[lp] = c
 
-    def _activate(self, rid: int):
+    def _activate(self, rid: int, cause: str):
         """Mark the request active: pull every page it references LOCAL
         (adopted prefix pages may sit on another tier) and pin them there —
         a pinned page is never offloaded by another sharer's park. All
-        planes' page-ins ride ONE coalesced message per (tier, donor)."""
+        planes' page-ins ride ONE coalesced message per (tier, donor).
+        A page-in that moves pages is an ``aqua.kv.restore`` span, labelled
+        with the tiers it reads and the caller's ``cause``."""
         if rid in self._active:
             return
         self._active.add(rid)
-        with self.meter.coalesce():
+        away = np.concatenate([plane.aqua.page_table[plane.flat(rid), 0]
+                               for plane in self.planes.values()])
+        away = away[away != LOCAL]
+        span = nullcontext()
+        if len(away):
+            span = TraceAnnotation(
+                "aqua.kv.restore", rid=rid, pages=len(away), cause=cause,
+                tier="+".join(TIER_NAMES[int(t)] for t in np.unique(away)))
+        with span, self.meter.coalesce():
             for plane in self.planes.values():
                 lps = plane.flat(rid)
                 if len(lps):
@@ -353,7 +365,8 @@ class PagedStateRuntime:
                         plane.pin[lp] = plane.pin.get(lp, 0) + 1
 
     # -- allocation -------------------------------------------------------
-    def ensure_capacity(self, rid: int, n_tokens: int):
+    def ensure_capacity(self, rid: int, n_tokens: int, *,
+                        cause: str = "admit"):
         """Grow the request's block tables to cover ``n_tokens`` of context.
 
         Token planes add pages as the context crosses page boundaries
@@ -361,7 +374,8 @@ class PagedStateRuntime:
         need); state planes allocate their fixed page set on first touch
         (zeroed — a freed slot may hold a previous occupant's state, and the
         zero page IS the initial recurrent state). Implicitly activates the
-        request: its existing pages are pulled LOCAL and pinned.
+        request: its existing pages are pulled LOCAL and pinned (a restore
+        labelled ``cause``).
 
         New pages must be LOCAL (the step programs read the LOCAL pools): if
         the allocator had to spill a fresh page to another tier the LOCAL
@@ -378,7 +392,7 @@ class PagedStateRuntime:
         Raises:
             MemoryError: a fresh page cannot be placed (or kept) LOCAL.
         """
-        self._activate(rid)
+        self._activate(rid, cause)
         added: List[Tuple[_Plane, List[int], int]] = []
         fresh_rids: List[_Plane] = []     # planes whose rows this call made
         try:
@@ -947,7 +961,8 @@ class PagedStateRuntime:
         return out
 
     # -- tier migration (preempt / restore as page-table flips) ------------
-    def park(self, rid: int, n_tokens: int, *, prefer: int = REMOTE):
+    def park(self, rid: int, n_tokens: int, *, prefer: int = REMOTE,
+             cause: str = "preempt"):
         """Preempt: flip the request's pages out of LOCAL — ALL planes fused
         into one coalesced message per (tier, donor) group (a hybrid's kv +
         ssm + conv pages ride one staging buffer, not one message per
@@ -964,8 +979,14 @@ class PagedStateRuntime:
         offloaded — a shared prefix page leaves LOCAL when its LAST active
         referencer parks, and is metered full (its payload is complete
         whatever this request's own resident prefix is).
+
+        Every park is an ``aqua.kv.park`` span labelled with the request,
+        the pages it moved off LOCAL, the tier preferred and the caller's
+        ``cause`` (preempt, spec, mispredict, drain).
         """
-        with self.meter.coalesce():
+        moved = 0
+        with TraceAnnotation("aqua.kv.park", rid=rid, tier=TIER_NAMES[prefer],
+                             cause=cause) as span, self.meter.coalesce():
             for plane in self.planes.values():
                 if rid not in plane.pages:
                     continue
@@ -983,18 +1004,22 @@ class PagedStateRuntime:
                 if rid in self._active:
                     for lp in lps:
                         self._unpin(plane, int(lp))
-                victims = [int(lp) for lp in lps
-                           if plane.pin.get(int(lp), 0) == 0]
-                if victims:
-                    plane.aqua.offload(np.asarray(victims, np.int64),
-                                       prefer=prefer)
+                victims = np.asarray([int(lp) for lp in lps
+                                      if plane.pin.get(int(lp), 0) == 0],
+                                     np.int64)
+                if len(victims):
+                    moved += int((plane.aqua.page_table[victims, 0]
+                                  == LOCAL).sum())
+                    plane.aqua.offload(victims, prefer=prefer)
+            span.set_metadata(pages=moved)
         self._active.discard(rid)
 
-    def restore(self, rid: int):
+    def restore(self, rid: int, *, cause: str = "admit"):
         """Make every page of the request LOCAL and pin it there (no bytes
         move for pages a still-active sharer kept LOCAL); resets token-page
-        fills to 1.0. No-op when the request is already active."""
-        self._activate(rid)
+        fills to 1.0. No-op when the request is already active. ``cause``
+        (admit, prefetch) labels the ``aqua.kv.restore`` span."""
+        self._activate(rid, cause)
 
     def nonlocal_pages(self, rid: int) -> np.ndarray:
         """Per-plane pages of the request currently NOT in the LOCAL tier."""

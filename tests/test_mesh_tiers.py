@@ -327,6 +327,35 @@ def test_warm_legs_record_fabric_samples():
     assert all(b > 0 and t > 0 for b, t in dom.samples["fabric"])
 
 
+def test_legs_are_named_programs_and_spans(tmp_path):
+    """A profile finds each fabric leg by name: the push and pull programs
+    are the XLA modules ``jit_aqua_mesh_push`` / ``jit_aqua_mesh_pull``,
+    and each leg is a host span ``aqua.mesh.push`` / ``aqua.mesh.pull``
+    inside the tier move's ``aqua.tier.scatter`` / ``aqua.tier.gather``."""
+    from jax.profiler import ProfileData
+    dom = MeshTierDomain()
+    a = _tensor(dom)
+    lps = a.allocate(3)
+    a.write_local(lps, jnp.ones((3,) + a.page_shape, jnp.float32))
+    with jax.profiler.trace(str(tmp_path)):
+        a.offload(lps, prefer=REMOTE)
+        a.ensure_local(lps)
+    pd = ProfileData.from_file(
+        str(next(tmp_path.glob("plugins/profile/*/*.xplane.pb"))))
+    modules, host = set(), {}
+    for plane in pd.planes:
+        for line in plane.lines:
+            for e in line.events:
+                host.setdefault(e.name, []).append(
+                    (e.start_ns, e.start_ns + e.duration_ns))
+                modules |= {str(v) for k, v in e.stats if k == "hlo_module"}
+    assert {"jit_aqua_mesh_push", "jit_aqua_mesh_pull"} <= modules
+    for leg, move in (("push", "scatter"), ("pull", "gather")):
+        [(s, e)] = host[f"aqua.mesh.{leg}"]
+        assert any(ms <= s and e <= me
+                   for ms, me in host[f"aqua.tier.{move}"])
+
+
 def test_fit_link_model_recovers_known_link():
     alpha, bw = 5e-6, 100e9
     sizes = [1 << 16, 1 << 18, 1 << 20, 1 << 22]

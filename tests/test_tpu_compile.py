@@ -119,7 +119,7 @@ def test_fused_step_compiles_and_fits_one_chip(one_chip, compiled_kernels):
         lambda l: _sds(one_chip, l.shape, l.dtype), api.param_specs(cfg))
     pools = {"kv": _sds(one_chip, (slots,) + _kv_page(cfg), jnp.bfloat16)}
     tables = {"kv": _sds(one_chip, (cfg.n_layers, 1, R, pps_pad), jnp.int32)}
-    step = lm._serve_step_jit(cfg, "pallas", pps, max_running)
+    step = lm._serve_step_jit(cfg, "pallas", pps, max_running, "mixed")
     c = step.lower(params, _sds(one_chip, (R, Tc), jnp.int32), pools, tables,
                    _sds(one_chip, (R,), jnp.int32),
                    _sds(one_chip, (R,), jnp.int32), None).compile()
