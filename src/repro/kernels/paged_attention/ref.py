@@ -54,8 +54,10 @@ def paged_attention_pool_ref(q, kv_pool, block_tables, lengths,
     """
     k_pages = jnp.moveaxis(kv_pool[:, 0], 1, 0)       # (K, P, page, hd)
     v_pages = jnp.moveaxis(kv_pool[:, 1], 1, 0)
-    return paged_attention_ref(q, k_pages, v_pages, block_tables, lengths,
-                               scale=scale)
+    out = paged_attention_ref(q, k_pages, v_pages, block_tables, lengths,
+                              scale=scale)
+    # an empty sequence sees no key and reads zeros
+    return jnp.where((lengths > 0)[:, None, None], out, 0).astype(out.dtype)
 
 
 def paged_prefill_attention_pool_ref(q, kv_pool, block_tables, q_starts,
@@ -94,7 +96,7 @@ def paged_mixed_attention_pool_ref(q, kv_pool, block_tables, q_starts,
     q: (R,Tc,H,hd); kv_pool: (P,2,K,page,hd); block_tables: (R,pps);
     q_starts/n_reals/is_decode: (R,) per-row metadata — a decode lane is a
     one-token row (n_real 1) at absolute position q_start whose tail rows
-    are fully masked (finite uniform-mean garbage, never read); a chunk
+    see no key and read zeros (never read by the caller); a chunk
     row attends causally at every row INCLUDING bucket padding, matching
     the per-request chunk kernel bit-exactly (garbage rows' K/V sits in
     the page window until later chunks overwrite it).
@@ -120,7 +122,9 @@ def paged_mixed_attention_pool_ref(q, kv_pool, block_tables, q_starts,
     valid = (k_pos <= q_pos) \
         & (~dec | (t < n_reals[:, None]))[:, None, None, :, None]
     scores = jnp.where(valid, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    probs = jax.nn.softmax(scores, axis=-1)
+    # a row that sees no key (a decode lane's tail) reads zeros
+    probs = jnp.where(valid.any(-1, keepdims=True), probs, 0).astype(q.dtype)
     out = jnp.einsum("bkgts,bksd->bkgtd", probs, vg)
     return out.transpose(0, 3, 1, 2, 4).reshape(R, Tc, H, hd)
 

@@ -80,6 +80,7 @@ from repro.core.errors import (CancelledError, EngineCrashError,
 from repro.core.faults import InvariantAuditor
 from repro.core.perfmodel import (HardwareProfile, ModelCost, TPU_V5E,
                                   overlapped_transfer_time)
+from repro.kernels.paged_attention.kernel import last_pages
 from repro.models import api, lm
 from repro.serving.kv_cache import PagedStateRuntime
 from repro.serving.scheduler import (CFSScheduler, Decision, FCFSScheduler,
@@ -111,6 +112,12 @@ class EngineMetrics:
     step_times: List[float] = field(default_factory=list)
     prefill_tokens_trace: List[int] = field(default_factory=list)
     fused_calls: int = 0                  # serve_step_paged dispatches
+    # paged-attention page steps of the fused steps, summed over kv layers:
+    # those the kernel walks (each row up to its last held page,
+    # ``kernel.last_pages``) and those of its grid (every row x every page
+    # of max_seq); 1 - held / grid is the share it skips
+    attn_pages_held: int = 0
+    attn_pages_grid: int = 0
     # fault-tolerance accounting (zero on a fault-free run): transfer-leg
     # retries absorbed by bounded backoff, donor losses / lease shrinks
     # applied, pages live-migrated off shrinking donors, and the requests
@@ -1224,6 +1231,14 @@ class ServingEngine:
                 pre = jnp.concatenate([p if p is not None else zero
                                        for p in prefix_rows], axis=0)
             bt = self.kv.block_tables(row_rids, pad_to=self._pps_pad)
+            kv_plane = self.kv.planes.get("kv")
+            if kv_plane is not None:
+                last = last_pages(q_starts, n_reals, np.arange(R) < n_dec,
+                                  tc=Tc, page=self.kv.page_tokens,
+                                  pps=self.kv.pps)
+                layers = kv_plane.n_layers
+                m.attn_pages_held += layers * int(last.sum() + R)
+                m.attn_pages_grid += layers * R * self.kv.pps
             tokens, q_starts, n_reals = (jnp.asarray(tokens),
                                          jnp.asarray(q_starts),
                                          jnp.asarray(n_reals))

@@ -11,8 +11,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import get_config, smoke_config
+from repro.configs import get_config, list_archs, smoke_config
 from repro.core.aqua_tensor import HOST, REMOTE
+from repro.kernels.paged_attention import kernel
 from repro.kernels.paged_attention.kernel import (
     paged_attention_pool, paged_mixed_attention_pool,
     paged_prefill_attention_pool)
@@ -46,16 +47,45 @@ def _assert_close(a, b, err_msg=""):
 # ---------------------------------------------------------------------------
 # kernel: mixed-mode fused-pool variant
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_mixed_kernel_matches_ref(dtype):
+# rows of every kind, page = 8 over a table of 4 pages: decode lanes whose
+# context ends in the last page, in page 0, mid-table and on the first
+# token of a page, an empty decode lane (n_real 0: sees no key, reads
+# zeros); chunk rows crossing a page boundary, a bucket-pad row (n_real 0),
+# one ending in the last page, one whose padding runs past the table
+_MIXED_ROWS = dict(starts=[31, 0, 9, 16, 4, 5, 0, 24, 28],
+                   n_reals=[1, 1, 1, 1, 0, 6, 0, 8, 3],
+                   is_dec=[1, 1, 1, 1, 1, 0, 0, 0, 0])
+# R, Tc, H, K, hd, P, page, pps, rows
+_MIXED_SHAPES = {
+    "mha": (9, 8, 4, 4, 32, 12, 8, 4, _MIXED_ROWS),
+    "gqa": (9, 8, 8, 2, 32, 12, 8, 4, _MIXED_ROWS),
+    # G = 4 query heads per kv head at a 256-token step: 1024 rows per kv
+    # head, so VMEM_BUDGET splits the 8 kv heads over several grid steps
+    "split": (3, 256, 32, 8, 128, 8, 64, 5,
+              dict(starts=[300, 40, 0], n_reals=[1, 200, 0],
+                   is_dec=[1, 0, 0])),
+}
+
+
+@pytest.mark.parametrize("dtype,shape", [
+    pytest.param(jnp.float32, "mha", id="float32"),
+    pytest.param(jnp.bfloat16, "mha", id="bfloat16"),
+    pytest.param(jnp.float32, "gqa", id="gqa-float32"),
+    pytest.param(jnp.bfloat16, "gqa", id="gqa-bfloat16"),
+    pytest.param(jnp.float32, "split", id="split-heads-float32"),
+    pytest.param(jnp.bfloat16, "split", id="split-heads-bfloat16"),
+])
+def test_mixed_kernel_matches_ref(dtype, shape):
     rng = np.random.default_rng(0)
-    R, Tc, H, K, hd, P, page, pps = 4, 8, 4, 2, 32, 12, 8, 4
+    R, Tc, H, K, hd, P, page, pps, rows = _MIXED_SHAPES[shape]
+    if shape == "split":
+        hb = kernel.heads_per_step(K, Tc * (H // K), page, hd, dtype, dtype)
+        assert hb < K
     q = _rand(rng, (R, Tc, H, hd), dtype)
     pool = _rand(rng, (P, 2, K, page, hd), dtype)
     bt = jnp.asarray(rng.integers(0, P, (R, pps)), jnp.int32)
-    starts = jnp.asarray([5, 9, 0, 3], jnp.int32)
-    n_reals = jnp.asarray([1, 1, 6, 0], jnp.int32)   # 2 decode, chunk, pad
-    is_dec = jnp.asarray([1, 1, 0, 0], jnp.int32)
+    starts, n_reals, is_dec = (jnp.asarray(rows[k], jnp.int32)
+                               for k in ("starts", "n_reals", "is_dec"))
     out = paged_mixed_attention_pool(q, pool, bt, starts, n_reals, is_dec,
                                      interpret=True)
     ref = paged_mixed_attention_pool_ref(q, pool, bt, starts, n_reals,
@@ -86,6 +116,32 @@ def test_mixed_kernel_rows_bit_identical_to_per_mode_kernels():
     ch = paged_prefill_attention_pool(q[3:], pool, bt[3:], starts[3:],
                                       interpret=True)
     np.testing.assert_array_equal(np.asarray(out[3:]), np.asarray(ch))
+
+
+def _kv_archs():
+    return [a for a in list_archs()
+            if lm.supports_paged(get_config(a))
+            and "kv" in lm.paged_layout(get_config(a))]
+
+
+@pytest.mark.parametrize("arch", _kv_archs())
+def test_heads_per_step_divides_k_and_fits_the_budget(arch):
+    """At the engine's step shapes (all-decode Tc = 1, a 256-token step
+    budget) and its page sizes (8 by default, 64 in the benchmark), the
+    kv heads a grid step carries divide K and their reckoned VMEM fits the
+    budget; qwen1.5-0.5b carries all 16 at every one."""
+    cfg = get_config(arch)
+    K, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+    G = cfg.n_heads // K
+    dt = jnp.dtype(cfg.compute_dtype)
+    for Tc in (1, 256):
+        for page in (8, 64):
+            hb = kernel.heads_per_step(K, Tc * G, page, hd, dt, dt)
+            assert K % hb == 0
+            assert (kernel.pool_working_set(hb, Tc * G, page, hd, dt, dt)
+                    <= kernel.VMEM_BUDGET)
+            if arch == "qwen1.5-0.5b":
+                assert hb == 16
 
 
 # ---------------------------------------------------------------------------
@@ -293,6 +349,46 @@ def test_engine_issues_one_call_per_step_and_matches_greedy(monkeypatch):
     assert m.fused_calls == len(calls) == len(steps_with_work)
     assert max(replaced for _, replaced in calls) > 1
     assert m.prefills > len(prompts)                  # chunking really ran
+
+
+def test_attention_page_counters_follow_the_rows_last_pages(monkeypatch):
+    """``attn_pages_held`` / ``attn_pages_grid`` add up, per fused step and
+    kv layer, the pages each packed row's attention walks (through its last
+    page: a decode row's ``q_start // page``, a chunk row's
+    ``(q_start + Tc - 1) // page``, clamped to the table) and those of the
+    kernel's grid (every row x every page)."""
+    cfg = smoke_config(get_config(ARCH))
+    params = api.init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(4)
+    eng = ServingEngine(cfg, params, max_running=2, max_seq=64,
+                        scheduler="cfs", slice_tokens=3, offload_tier=HOST,
+                        step_tokens=13)
+    steps = []
+    dispatch = api.serve_step_paged
+
+    def recorded(*a, **kw):
+        steps.append((a[2].shape, np.asarray(a[5]), np.asarray(a[6]),
+                      kw["n_decode"], kw["read_pps"]))
+        return dispatch(*a, **kw)
+
+    monkeypatch.setattr(api, "serve_step_paged", recorded)
+    for n in (19, 11, 40):
+        eng.submit(list(map(int, rng.integers(0, cfg.vocab_size, n))), 4)
+    m = eng.run(400)
+    layers = eng.kv.planes["kv"].n_layers
+    page = eng.kv.page_tokens
+    held = grid = 0
+    for (R, Tc), q_starts, n_reals, n_dec, pps in steps:
+        for r in range(R):
+            if r < n_dec:
+                last = q_starts[r] // page if n_reals[r] else 0
+            else:
+                last = (q_starts[r] + Tc - 1) // page
+            held += layers * (min(last, pps - 1) + 1)
+        grid += layers * R * pps
+    assert steps and len(steps) == m.fused_calls
+    assert (m.attn_pages_held, m.attn_pages_grid) == (held, grid)
+    assert 0 < m.attn_pages_held < m.attn_pages_grid
 
 
 # ---------------------------------------------------------------------------

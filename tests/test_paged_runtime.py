@@ -12,6 +12,7 @@ import pytest
 
 from repro.configs import get_config, smoke_config
 from repro.core.aqua_tensor import HOST, LOCAL, REMOTE, AquaTensor
+from repro.kernels.paged_attention import kernel
 from repro.kernels.paged_attention.kernel import (append_kv,
                                                   paged_attention_pool)
 from repro.kernels.paged_attention.ref import (append_kv_ref,
@@ -35,13 +36,23 @@ def _rand(rng, shape, dtype):
     (2, 4, 2, 64, 16, 8, 4),
     (3, 6, 2, 32, 32, 16, 6),
     (4, 8, 1, 64, 64, 32, 4),               # MQA
+    (5, 4, 4, 32, 24, 8, 6),                # MHA, every kind of row
+    (5, 16, 2, 32, 24, 8, 6),               # GQA, every kind of row
+    (2, 8192, 8, 128, 12, 8, 3),            # the budget splits the heads
 ])
 def test_paged_attention_pool_matches_ref(B, H, K, hd, P, page, pps, dtype):
+    """Rows, in order: a context ending in the table's last page, one in
+    page 0, one mid-table, an empty row (reads zeros), one ending on the
+    first token of the second page. The kernel walks each row only up to
+    its last page."""
     rng = np.random.default_rng(0)
     q = _rand(rng, (B, H, hd), dtype)
     pool = _rand(rng, (P, 2, K, page, hd), dtype)
     bt = jnp.asarray(rng.integers(0, P, (B, pps)), jnp.int32)
-    ln = jnp.asarray(rng.integers(1, pps * page + 1, (B,)), jnp.int32)
+    edges = [pps * page, 1, (pps // 2) * page + page // 2, 0, page + 1]
+    ln = jnp.asarray(edges[:B], jnp.int32)
+    if H == 8192:
+        assert kernel.heads_per_step(K, H // K, page, hd, dtype, dtype) < K
     out = paged_attention_pool(q, pool, bt, ln, interpret=True)
     ref = paged_attention_pool_ref(q, pool, bt, ln)
     tol = 3e-5 if dtype == jnp.float32 else 3e-2

@@ -44,14 +44,22 @@ def _greedy(cfg, params, prompt, n, max_seq=64):
 # ---------------------------------------------------------------------------
 # kernel: query-block fused-pool variant
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_chunk_kernel_matches_ref(dtype):
+@pytest.mark.parametrize("dtype,H", [
+    pytest.param(jnp.float32, 4, id="float32"),       # GQA, 2 per kv head
+    pytest.param(jnp.bfloat16, 4, id="bfloat16"),
+    pytest.param(jnp.float32, 2, id="mha-float32"),
+    pytest.param(jnp.bfloat16, 2, id="mha-bfloat16"),
+])
+def test_chunk_kernel_matches_ref(dtype, H):
     rng = np.random.default_rng(0)
-    B, Tc, H, K, hd, P, page, pps = 2, 6, 4, 2, 32, 16, 8, 4
+    B, Tc, K, hd, P, page, pps = 5, 6, 2, 32, 16, 8, 4
     q = _rand(rng, (B, Tc, H, hd), dtype)
     pool = _rand(rng, (P, 2, K, page, hd), dtype)
     bt = jnp.asarray(rng.integers(0, P, (B, pps)), jnp.int32)
-    starts = jnp.asarray([3, 10], jnp.int32)          # mid-page chunk starts
+    # chunks crossing a page boundary, mid-table, in page 0, ending in the
+    # last page, and padded past the table; the kernel walks each row only
+    # up to the page its last (pad) token sits in
+    starts = jnp.asarray([3, 10, 0, 26, 29], jnp.int32)
     out = paged_prefill_attention_pool(q, pool, bt, starts, interpret=True)
     ref = paged_prefill_attention_pool_ref(q, pool, bt, starts)
     tol = 3e-5 if dtype == jnp.float32 else 3e-2

@@ -56,8 +56,8 @@ def _sds(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _kv_page(cfg):
-    return (2, cfg.n_kv_heads, PAGE, cfg.resolved_head_dim)
+def _kv_page(cfg, page=PAGE):
+    return (2, cfg.n_kv_heads, page, cfg.resolved_head_dim)
 
 
 def _compile(fn, *args):
@@ -76,13 +76,22 @@ def test_append_kv_compiles_in_bf16(one_chip):
     assert "tpu_custom_call" in c.as_text()
 
 
-def test_mixed_attention_kernel_compiles_in_bf16(one_chip):
-    cfg = get_config(ARCH)
-    R, Tc, pps = 12, 256, 64
+@pytest.mark.parametrize("arch,page", [
+    pytest.param(ARCH, PAGE, id=ARCH),
+    # the benchmark's pages: the largest reckoned working set, 15 MiB
+    pytest.param(ARCH, 64, id=f"{ARCH}-page64"),
+    # 36 kv heads: VMEM_BUDGET splits them, 12 per grid step
+    pytest.param("minicpm-2b", PAGE, id="minicpm-2b"),
+])
+def test_mixed_attention_kernel_compiles_in_bf16(one_chip, arch, page):
+    """A 256-token mixed step of 12 rows: the VMEM each grid step takes
+    (``kernel.heads_per_step``) must fit the scoped limit Mosaic is given."""
+    cfg = get_config(arch)
+    R, Tc, pps = 12, 256, 512 // page
     c = _compile(pa_kernel.paged_mixed_attention_pool,
                  _sds(one_chip, (R, Tc, cfg.n_heads, cfg.resolved_head_dim),
                       jnp.bfloat16),
-                 _sds(one_chip, (1025,) + _kv_page(cfg), jnp.bfloat16),
+                 _sds(one_chip, (1025,) + _kv_page(cfg, page), jnp.bfloat16),
                  _sds(one_chip, (R, pps), jnp.int32),
                  _sds(one_chip, (R,), jnp.int32),
                  _sds(one_chip, (R,), jnp.int32),
